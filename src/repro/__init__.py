@@ -1,8 +1,1 @@
-"""repro — SEE-MCAM reproduction + production jax_pallas serving/training stack.
-
-Importing any ``repro.*`` module first routes through here, which installs the
-:mod:`repro.dist.compat` JAX API bridge so model, launcher and test code can
-target the modern mesh surface regardless of the installed jax version.
-"""
-
-from repro.dist import compat as _compat  # noqa: F401
+"""repro — SEE-MCAM reproduction + production jax_pallas serving/training stack."""

@@ -359,7 +359,7 @@ BackendFn = Callable[[jnp.ndarray, jnp.ndarray, int, str], jnp.ndarray]
 FusedBackendFn = Callable[..., tuple[jnp.ndarray, jnp.ndarray]]
 
 #: Largest ``k`` routed to a backend's fused tier.  The streaming kernel's
-#: per-block fold is a bitonic merge network — O(log^2(k + bn))
+#: per-block fold is a bitonic merge network — O(log^2 bn + log k)
 #: compare-exchange stages, not the k sequential argmin rounds that once
 #: capped this at 64 — so the ceiling now sits where the (bq, k) running
 #: state stops paying for itself in VMEM; beyond it the dense tier +
@@ -1345,10 +1345,9 @@ def search_sharded(table: AMTable, queries, *, mesh, rules=None, k: int = 1,
         # exact global match count: each bank counted disjoint rows
         return gi, dl, jax.lax.psum(cl, axis)
 
-    # Outputs are replicated over `model` by construction (both merges end
-    # with every bank holding the same candidates), but 0.4.x's replication
-    # checker can't see through the collective -> sort/top_k chain, so the
-    # check is disabled.
+    # Outputs are replicated over `model` by construction (every merge ends
+    # with each bank holding the same candidates).  The replication check
+    # cannot follow that through a pallas_call or the merge, so it is off.
     args = [codes, queries, vr]
     in_specs = [rules.am_table(), q_spec, P()]
     out_specs = [P(out_batch, None), P(out_batch, None)]

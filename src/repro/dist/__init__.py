@@ -1,12 +1,7 @@
 """``repro.dist`` — the distribution layer: sharding rules + pipeline schedules.
 
-Design: a thin *rule engine* rather than a framework.  The package has three
+Design: a thin *rule engine* rather than a framework.  The package has two
 parts, each usable alone:
-
-* :mod:`repro.dist.compat` — bridges this jax's API surface up to the modern
-  mesh names (``jax.set_mesh``, ``jax.shard_map``, ``AxisType``) so the same
-  model code runs on the pinned container jaxlib and on current releases.
-  Imported first; everything below assumes the modern surface.
 
 * :mod:`repro.dist.specs` — the sharding-rule engine.  ``make_rules(mesh,
   layout)`` returns an immutable :class:`~repro.dist.specs.Rules` whose
@@ -25,6 +20,5 @@ parts, each usable alone:
   while stages rotate activations manually.
 """
 
-from repro.dist import compat  # noqa: F401  — install API bridge on import
 from repro.dist.pipeline import bubble_fraction, make_pp_forward  # noqa: F401
 from repro.dist.specs import Rules, constrain, make_rules  # noqa: F401

@@ -1,138 +1,20 @@
-"""JAX API version bridge for the ``repro.dist`` subsystem.
+"""Ambient-state probes used by :func:`repro.dist.specs.constrain`.
 
-The distribution layer (and everything downstream of it: models, launchers,
-serving, tests) is written against the modern mesh API surface —
-``jax.set_mesh``, ``jax.shard_map``, ``jax.make_mesh(..., axis_types=...)``
-and ``jax.sharding.AxisType``.  Older jaxlibs (this container ships 0.4.x)
-expose the same functionality under different names:
-
-  ===========================  =============================================
-  modern API                   0.4.x equivalent
-  ===========================  =============================================
-  ``jax.set_mesh(mesh)``       the legacy ``with mesh:`` resource context
-  ``jax.shard_map(...)``       ``jax.experimental.shard_map.shard_map`` with
-                               ``check_rep`` / ``auto`` instead of
-                               ``check_vma`` / ``axis_names``
-  ``jax.make_mesh(axis_types=...)``  same call without ``axis_types``
-  ``jax.sharding.AxisType``    implicit (every axis is GSPMD-auto)
-  ===========================  =============================================
-
-``install()`` fills each missing attribute in place, strictly additively: a
-jax that already provides the modern names is left untouched, so this module
-is a no-op on current releases.  It is invoked from ``repro/__init__.py`` so
-any ``import repro.<anything>`` guarantees the surface exists before model or
-test code touches it.
+``constrain`` must know whether a mesh is current and whether it is being
+traced inside a manual (``shard_map``) region; JAX answers both, the second
+only through an internal module.  Both probes live here so that a change of
+JAX's internals touches one file.
 """
 
 from __future__ import annotations
 
-import enum
-import functools
-import inspect
-
 import jax
 
 
-def _install_axis_type() -> None:
-    if hasattr(jax.sharding, "AxisType"):
-        return
-
-    class AxisType(enum.Enum):
-        """Stand-in for ``jax.sharding.AxisType`` (0.4.x is implicitly Auto)."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    jax.sharding.AxisType = AxisType
-
-
-def _install_make_mesh() -> None:
-    params = inspect.signature(jax.make_mesh).parameters
-    if "axis_types" in params:
-        return
-    orig = jax.make_mesh
-
-    @functools.wraps(orig)
-    def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-        """Accept and drop ``axis_types`` on 0.4.x (always GSPMD-auto).
-
-        Manual/Explicit requests only arrive from shard_map, which handles
-        them itself, so the kwarg exists purely for source compatibility.
-        """
-        del axis_types
-        return orig(axis_shapes, axis_names, devices=devices)
-
-    jax.make_mesh = make_mesh
-
-
-def _install_set_mesh() -> None:
-    if hasattr(jax, "set_mesh"):
-        return
-
-    def set_mesh(mesh):
-        """Return ``mesh`` itself as the ``with jax.set_mesh(mesh):`` context.
-
-        On 0.4.x the legacy mesh resource context already makes bare
-        ``PartitionSpec``s resolvable, so the mesh (a context manager) is
-        the right object to return.
-        """
-        return mesh
-
-    jax.set_mesh = set_mesh
-
-
-def _install_shard_map() -> None:
-    if hasattr(jax, "shard_map"):
-        return
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=True, **kwargs):
-        """Modern keyword surface on top of the experimental implementation.
-
-        ``axis_names`` (the set of axes the body is manual over) maps to the
-        legacy ``auto`` complement; ``check_vma`` maps to ``check_rep``.
-        """
-        if axis_names:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        else:
-            auto = frozenset()
-        return legacy_shard_map(f, mesh, in_specs=in_specs,
-                                out_specs=out_specs, check_rep=check_vma,
-                                auto=auto, **kwargs)
-
-    jax.shard_map = shard_map
-
-
-def install() -> None:
-    """Idempotently bridge missing modern-API names onto this jax."""
-    _install_axis_type()
-    _install_make_mesh()
-    _install_set_mesh()
-    _install_shard_map()
-
-
-# ---------------------------------------------------------------------------
-# Ambient-state probes used by ``repro.dist.specs.constrain``
-# ---------------------------------------------------------------------------
-
 def ambient_mesh():
-    """The mesh made current by ``jax.set_mesh`` / ``with mesh:``, or None.
-
-    Works on both API generations: the modern abstract-mesh context and the
-    0.4.x thread-resource environment.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        return None if (mesh is None or mesh.empty) else mesh
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # pragma: no cover - internal layout changed
-        return None
+    """The mesh made current by ``jax.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if (mesh is None or mesh.empty) else mesh
 
 
 def in_manual_region() -> bool:
@@ -146,6 +28,3 @@ def in_manual_region() -> bool:
         return bool(jcore.get_axis_env().axis_sizes)
     except Exception:  # pragma: no cover - internal layout changed
         return False
-
-
-install()

@@ -25,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (ensures jax.shard_map exists)
-
 PP_AXIS = "pod"
 
 

@@ -25,15 +25,16 @@ Two kernels share that tiling:
   matrix to HBM (callers run their own ``lax.top_k``).
 * :func:`cam_search_topk` — the fused/streaming tier: the same grid with the
   N axis as the streaming (inner-of-Q) loop; each N block's distances are
-  folded into a running per-query top-k held in a (bq, k) VMEM scratch and
+  folded into a running per-query top-k held in a (bq, >= k) VMEM scratch and
   the (bq, bn) distance block never leaves VMEM, so HBM output drops from
   O(Q*N) to O(Q*k).  A prefetched ``valid_rows`` scalar masks dead slab
   rows in-kernel (distance +inf), and ties are broken by lowest global row
   index — bitwise the ordering of ``lax.top_k`` over the dense matrix.
   The per-block fold is an in-register **bitonic merge network**
-  (:func:`_bitonic_topk_merge`): O(log^2(k+bn)) compare-exchange stages
-  built from reshape/min/max/where only — no ``sort``/``top_k`` primitives
-  — which is what lets the fused tier reach k = 256
+  (:func:`_bitonic_topk_merge`): O(log^2 bn + log k) compare-exchange
+  stages built from lane rotations and selects only — no ``sort``/``top_k``
+  primitives, no reversal, no lane-splitting reshape, all of which Mosaic
+  refuses — which is what lets the fused tier reach k = 256
   (``am.FUSED_K_MAX``) instead of the k = 64 the original k-round argmin
   selection (kept as ``merge_alg="argmin"``) could afford.
 
@@ -71,8 +72,13 @@ def _accumulate(q, t, c, acc, levels: int):
     ``1[q != t]`` summed over D — the same integers the unmasked path's
     ``D - #matches`` finalisation produces, so all-care masked search is
     bitwise-identical to unmasked search while sharing none of its trace.
+
+    Symbols and care flags travel as int8 but are compared as int32: the
+    TPU vector unit has no int8 compare.
     """
-    care = None if c is None else (c != 0)
+    q = q.astype(jnp.int32)
+    t = t.astype(jnp.int32)
+    care = None if c is None else (c.astype(jnp.int32) != 0)
     for m in range(levels):
         a = (q == m).astype(jnp.bfloat16)
         if care is None:
@@ -169,7 +175,7 @@ _NO_ROW = 2**31 - 1
 
 
 #: Merge networks ``cam_search_topk`` can fold candidates with.  The default
-#: ``"bitonic"`` is O(log^2(k+bn)) compare-exchange stages per block;
+#: ``"bitonic"`` is O(log^2 bn + log k) compare-exchange stages per block;
 #: ``"argmin"`` is the original k-sequential-round selection, kept callable
 #: as the semantic oracle and the benchmark baseline
 #: (``benchmarks/bench_am_topk.py`` k-sweep).
@@ -219,116 +225,129 @@ def _lex_lt(d_a, i_a, d_b, i_b):
     return (d_a < d_b) | ((d_a == d_b) & (i_a < i_b))
 
 
-def _compare_exchange(d, i, j: int, asc):
+def _lane_bit(lane, j: int):
+    """``(lane & j) != 0`` as int32 0/1, for a power-of-two ``j``."""
+    return (lane >> (j.bit_length() - 1)) & 1
+
+
+def _compare_exchange(d, i, lane, j: int, dir_bit):
     """One bitonic compare-exchange step at pair distance ``j``.
 
-    Pairs element ``x`` with ``x ^ j`` along the last axis via a reshape to
-    (..., L/(2j), 2, j) — no gathers, so the step is a handful of
-    min/max/where ops the VPU lowers directly.  ``asc`` is a (L/(2j),) bool
-    choosing each pair-block's direction (True = ascending).  Elements are
-    (distance, row-index) pairs under the :func:`_lex_lt` total order; equal
-    pairs are never swapped either way, so the network is deterministic and
+    Element ``x`` meets its partner ``x ^ j`` through two lane rotations
+    (``x + j`` and ``x - j``) and a select on ``lane & j`` — no gathers, no
+    reshape, so the step is a handful of rotate/compare/select ops the VPU
+    lowers directly.  ``dir_bit`` is an int32 0/1 array (or 0) marking the
+    lanes whose pair block sorts descending.  Elements are (distance,
+    row-index) pairs under the :func:`_lex_lt` total order; equal pairs are
+    never swapped either way, so the network is deterministic and
     order-stable on sentinel plateaus.
     """
-    bq, ln = d.shape
-    d4 = d.reshape(bq, ln // (2 * j), 2, j)
-    i4 = i.reshape(bq, ln // (2 * j), 2, j)
-    d_lo, d_hi = d4[:, :, 0, :], d4[:, :, 1, :]
-    i_lo, i_hi = i4[:, :, 0, :], i4[:, :, 1, :]
-    hi_first = _lex_lt(d_hi, i_hi, d_lo, i_lo)
-    lo_first = _lex_lt(d_lo, i_lo, d_hi, i_hi)
-    swap = jnp.where(asc[None, :, None], hi_first, lo_first)
-    nd = jnp.stack([jnp.where(swap, d_hi, d_lo),
-                    jnp.where(swap, d_lo, d_hi)], axis=2)
-    ni = jnp.stack([jnp.where(swap, i_hi, i_lo),
-                    jnp.where(swap, i_lo, i_hi)], axis=2)
-    return nd.reshape(bq, ln), ni.reshape(bq, ln)
+    ln = d.shape[-1]
+    hi = _lane_bit(lane, j)
+    up = hi == 0                              # partner sits at x + j
+    p_d = jnp.where(up, pltpu.roll(d, ln - j, 1), pltpu.roll(d, j, 1))
+    p_i = jnp.where(up, pltpu.roll(i, ln - j, 1), pltpu.roll(i, j, 1))
+    keep_min = (hi ^ dir_bit) == 0
+    take = ((keep_min & _lex_lt(p_d, p_i, d, i))
+            | (~keep_min & _lex_lt(d, i, p_d, p_i)))
+    return jnp.where(take, p_d, d), jnp.where(take, p_i, i)
 
 
-def _bitonic_sort(d, i):
+def _bitonic_sort(d, i, *, descending: bool = False):
     """Full in-register bitonic sort of (bq, L) pairs, L a power of two.
 
-    Ascending (distance, row index) — the classic network: stage ``size``
-    builds sorted runs of that length, alternating direction per
-    ``size``-block so adjacent runs form bitonic sequences for the next
-    stage.  O(log^2 L) compare-exchange steps, each a constant number of
-    vector ops.
+    The classic network: stage ``size`` builds sorted runs of that length,
+    alternating direction per ``size``-block so adjacent runs form bitonic
+    sequences for the next stage.  ``descending`` flips every comparator,
+    which sorts the whole row the other way.  O(log^2 L) compare-exchange
+    steps, each a constant number of vector ops.
     """
-    ln = d.shape[1]
+    ln = d.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, d.ndim - 1)
     size = 2
     while size <= ln:
+        dir_bit = _lane_bit(lane, size) ^ int(descending)
         j = size // 2
         while j >= 1:
-            nb = ln // (2 * j)
-            asc = ((jnp.arange(nb) * 2 * j) & size) == 0
-            d, i = _compare_exchange(d, i, j, asc)
+            d, i = _compare_exchange(d, i, lane, j, dir_bit)
             j //= 2
         size *= 2
     return d, i
 
 
-def _bitonic_merge_sorted(d, i):
-    """Bitonic-merge a (bq, L) bitonic sequence into ascending order.
+def _bitonic_merge(d, i):
+    """Sort a (bq, L) bitonic sequence ascending; L a power of two.
 
-    ``L`` must be a power of two; the input rises then falls under the
-    :func:`_lex_lt` order (any rotation of that also works — the standard
-    bitonic-merge guarantee).  log2(L) compare-exchange steps.
+    The input rises then falls under the :func:`_lex_lt` order, or is any
+    rotation of such a sequence (the standard bitonic-merge guarantee).
+    log2(L) compare-exchange steps.
     """
-    ln = d.shape[1]
+    ln = d.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, d.shape, d.ndim - 1)
     j = ln // 2
     while j >= 1:
-        asc = jnp.ones((ln // (2 * j),), bool)
-        d, i = _compare_exchange(d, i, j, asc)
+        d, i = _compare_exchange(d, i, lane, j, 0)
         j //= 2
     return d, i
 
 
+def _pad_sentinels(d, i, width: int, *, front: bool = False):
+    """Widen (bq, L) pairs to ``width`` with (+inf, `_NO_ROW`) sentinels."""
+    bq, ln = d.shape
+    if ln == width:
+        return d, i
+    pd = jnp.full((bq, width - ln), jnp.inf, d.dtype)
+    pi = jnp.full((bq, width - ln), _NO_ROW, i.dtype)
+    if front:
+        return (jnp.concatenate([pd, d], axis=1),
+                jnp.concatenate([pi, i], axis=1))
+    return (jnp.concatenate([d, pd], axis=1),
+            jnp.concatenate([i, pi], axis=1))
+
+
+def _bitonic_width(k: int, bn: int) -> int:
+    """Running top-k width the bitonic merge keeps: a power of two >= k, bn.
+
+    Inside the kernel ``bn`` is 128, so every array the network touches is
+    a whole number of 128-lane vregs wide.
+    """
+    return _next_pow2(max(k, bn))
+
+
 def _bitonic_topk_merge(best_d, best_i, cand_d, cand_i, k: int):
-    """Fold (bq, bn) candidates into the sorted (bq, k) running top-k.
+    """Fold (bq, bn) candidates into the sorted (bq, kb) running top-k.
 
     The ``"bitonic"`` merge network — same contract as :func:`_topk_merge`
     (ascending (distance, row index), +inf/`_NO_ROW` sentinel slots rank
-    last, bitwise ``lax.top_k`` order) in O(log^2(k+bn)) compare-exchange
-    stages instead of k sequential argmin rounds:
+    last, bitwise ``lax.top_k`` order) in O(log^2 bn + log kb)
+    compare-exchange stages instead of k sequential argmin rounds:
 
-    1. bitonic-sort the (bq, bn) candidate block once (candidates arrive in
-       row order, not distance order);
-    2. concatenate the already-sorted running top-k, a sentinel plateau
-       padding the total length to a power of two, and the *reversed*
-       candidate block — ascending, plateau, descending: a bitonic
-       sequence;
-    3. one bitonic merge, then keep the first k columns.
+    1. bitonic-sort the candidate block *descending* (candidates arrive in
+       row order, not distance order), then front-pad it with sentinels to
+       the running list's width w — still descending;
+    2. the half-cleaner: the lane-wise minimum of the ascending running
+       list and the descending candidates holds the w smallest pairs of
+       both, as one bitonic sequence (it falls, then rises);
+    3. one bitonic merge sorts it; keep the first k columns.
 
     The running top-k is sorted by construction (the kernel initialises it
     to all-sentinel and this function returns sorted output), so the
     invariant holds inductively across N blocks.  ``best_d`` may have any
     width >= k and ``cand`` any width >= 1 — non-powers-of-two are padded
-    with (+inf, `_NO_ROW`) internally, which sort strictly after every
-    genuine candidate (including +inf-masked real rows, whose indices are
-    < `_NO_ROW`).
+    with (+inf, `_NO_ROW`) here, which sort strictly after every genuine
+    candidate (including +inf-masked real rows, whose indices are
+    < `_NO_ROW`).  Inside the kernel both widths are already powers of two
+    (:func:`_bitonic_width`), so no padding is traced there.
     """
-    bq, bn = cand_d.shape
-    pad_c = _next_pow2(bn) - bn
-    if pad_c:
-        cand_d = jnp.concatenate(
-            [cand_d, jnp.full((bq, pad_c), jnp.inf, cand_d.dtype)], axis=1)
-        cand_i = jnp.concatenate(
-            [cand_i, jnp.full((bq, pad_c), jnp.int32(_NO_ROW), cand_i.dtype)],
-            axis=1)
-    cand_d, cand_i = _bitonic_sort(cand_d, cand_i)
-
-    kb = best_d.shape[1]
-    ln = _next_pow2(kb + cand_d.shape[1])
-    pad_m = ln - kb - cand_d.shape[1]
-    seq_d = [best_d]
-    seq_i = [best_i]
-    if pad_m:
-        seq_d.append(jnp.full((bq, pad_m), jnp.inf, best_d.dtype))
-        seq_i.append(jnp.full((bq, pad_m), jnp.int32(_NO_ROW), best_i.dtype))
-    seq_d.append(cand_d[:, ::-1])
-    seq_i.append(cand_i[:, ::-1])
-    out_d, out_i = _bitonic_merge_sorted(jnp.concatenate(seq_d, axis=1),
-                                         jnp.concatenate(seq_i, axis=1))
+    w = _next_pow2(max(best_d.shape[1], cand_d.shape[1]))
+    best_d, best_i = _pad_sentinels(best_d, best_i, w)
+    cand_d, cand_i = _pad_sentinels(cand_d, cand_i,
+                                    _next_pow2(cand_d.shape[1]))
+    cand_d, cand_i = _bitonic_sort(cand_d, cand_i, descending=True)
+    cand_d, cand_i = _pad_sentinels(cand_d, cand_i, w, front=True)
+    take = _lex_lt(cand_d, cand_i, best_d, best_i)
+    out_d, out_i = _bitonic_merge(jnp.where(take, cand_d, best_d),
+                                  jnp.where(take, cand_i, best_i))
     return out_d[:, :k], out_i[:, :k]
 
 
@@ -336,7 +355,7 @@ def _bitonic_topk_merge(best_d, best_i, cand_d, cand_i, k: int):
 _MERGE_FNS = {"bitonic": _bitonic_topk_merge, "argmin": _topk_merge}
 
 
-def _cam_search_topk_kernel(vr_ref, *refs, levels: int, d_total: int, k: int,
+def _cam_search_topk_kernel(vr_ref, *refs, levels: int, d_total: int,
                             block_n: int, nj: int, nk: int, masked: bool,
                             counted: bool, merge_alg: str):
     it = iter(refs)
@@ -378,7 +397,8 @@ def _cam_search_topk_kernel(vr_ref, *refs, levels: int, d_total: int, k: int,
         cand_d = jnp.where(row < vr_ref[0], d, jnp.inf)   # dead/pad rows
         cand_i = jnp.broadcast_to(row, d.shape)
         best_d, best_i = _MERGE_FNS[merge_alg](
-            best_d_ref[...], best_i_ref[...], cand_d, cand_i, k)
+            best_d_ref[...], best_i_ref[...], cand_d, cand_i,
+            best_d_ref.shape[1])
         best_d_ref[...] = best_d
         best_i_ref[...] = best_i
         if counted:
@@ -423,9 +443,11 @@ def cam_search_topk(queries: jnp.ndarray, table: jnp.ndarray,
     over the table.  Returns a 2-tuple without ``count_le``, a 3-tuple with.
 
     ``merge_alg`` picks the per-block merge network (:data:`MERGE_ALGS`):
-    ``"bitonic"`` (default, O(log^2(k+bn)) compare-exchange stages) or
+    ``"bitonic"`` (default, O(log^2 bn + log k) compare-exchange stages) or
     ``"argmin"`` (the original k-round selection, kept as oracle/baseline).
     Both are bitwise-identical by construction; only the op count differs.
+    The bitonic network keeps :func:`_bitonic_width` >= k candidates per
+    query in VMEM and writes them all; the first k columns are the answer.
     """
     qn, d = queries.shape
     tn, d2 = table.shape
@@ -445,8 +467,9 @@ def cam_search_topk(queries: jnp.ndarray, table: jnp.ndarray,
         assert count_le.shape == (qn, 1), (count_le.shape, qn)
     nj, nk = tn // block_n, d // block_d
 
+    kw = _bitonic_width(k, block_n) if merge_alg == "bitonic" else k
     kernel = functools.partial(_cam_search_topk_kernel, levels=levels,
-                               d_total=d, k=k, block_n=block_n, nj=nj, nk=nk,
+                               d_total=d, block_n=block_n, nj=nj, nk=nk,
                                masked=masked, counted=counted,
                                merge_alg=merge_alg)
     in_specs = [
@@ -463,17 +486,17 @@ def cam_search_topk(queries: jnp.ndarray, table: jnp.ndarray,
                                      lambda i, j, kk, vr: (i, 0)))
         operands.append(count_le)
     out_specs = [
-        pl.BlockSpec((block_q, k), lambda i, j, kk, vr: (i, 0)),
-        pl.BlockSpec((block_q, k), lambda i, j, kk, vr: (i, 0)),
+        pl.BlockSpec((block_q, kw), lambda i, j, kk, vr: (i, 0)),
+        pl.BlockSpec((block_q, kw), lambda i, j, kk, vr: (i, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((qn, k), jnp.int32),
-        jax.ShapeDtypeStruct((qn, k), jnp.float32),
+        jax.ShapeDtypeStruct((qn, kw), jnp.int32),
+        jax.ShapeDtypeStruct((qn, kw), jnp.float32),
     ]
     scratch_shapes = [
         pltpu.VMEM((block_q, block_n), jnp.float32),
-        pltpu.VMEM((block_q, k), jnp.float32),
-        pltpu.VMEM((block_q, k), jnp.int32),
+        pltpu.VMEM((block_q, kw), jnp.float32),
+        pltpu.VMEM((block_q, kw), jnp.int32),
     ]
     if counted:
         out_specs.append(pl.BlockSpec((block_q, 1),
@@ -488,9 +511,10 @@ def cam_search_topk(queries: jnp.ndarray, table: jnp.ndarray,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
     )(jnp.asarray(valid_rows, jnp.int32).reshape(1), *operands)
+    return (out[0][:, :k], out[1][:, :k], *out[2:])

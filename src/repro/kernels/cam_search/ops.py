@@ -1,7 +1,8 @@
 """Jitted public wrapper around the CAM-search Pallas kernel.
 
 Handles padding to TPU-aligned block multiples, dtype normalisation, backend
-selection (interpret on CPU / compiled on TPU), and derived outputs
+selection (compiled on the TPU, interpreted on the CPU, refused anywhere
+else — :func:`repro.kernels.interpret_mode`), and derived outputs
 (exact-match flags, top-k / best-row readout).
 
 Distance-unit contract
@@ -55,11 +56,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.cam_search import kernel as _k
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int, value) -> jnp.ndarray:
@@ -84,16 +82,16 @@ def mismatch_counts(queries: jnp.ndarray, table: jnp.ndarray, bits: int = 3,
     (N, D) marks don't-care positions with 0 (never mismatches); its padded
     positions hold 0, so padding stays skew-free on the masked path too.
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     q = jnp.asarray(queries, jnp.int8)
     t = jnp.asarray(table, jnp.int8)
     qn, d = q.shape
     tn = t.shape[0]
 
-    # Small problems keep small blocks (still MXU-aligned on the lane dim).
+    # Small query batches keep 8-row blocks; table rows are the output's
+    # lane axis, so they always pad to 128-row blocks (sliced away below).
     bq = 128 if qn > 64 else 8
-    bn = 128 if tn > 64 else 8
+    bn = 128
     bd = 512 if d >= 512 else 128
 
     qp = _pad_to(_pad_to(q, 0, bq, 0), 1, bd, 0)
@@ -174,20 +172,21 @@ def topk_fused(queries: jnp.ndarray, table: jnp.ndarray, k: int = 1,
     switches on the in-kernel multi-match counter: the return value becomes
     a 3-tuple whose third element is (Q,) int32, the number of live rows at
     distance <= threshold per query.  ``merge_alg`` selects the in-kernel
-    per-block merge network (``"bitonic"``, the O(log^2(k+bn)) default, or
+    per-block merge network (``"bitonic"``, the O(log^2 bn + log k) default, or
     the original ``"argmin"`` k-round selection — bitwise-identical, kept
     for benchmarking; see ``kernel.MERGE_ALGS``).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = interpret_mode(interpret)
     q = jnp.asarray(queries, jnp.int8)
     t = jnp.asarray(table, jnp.int8)
     qn, d = q.shape
     tn = t.shape[0]
     k = min(k, tn)
 
+    # The merge network works on whole 128-lane candidate blocks, so the
+    # table always pads to 128-row blocks here (padded rows sit at +inf).
     bq = 128 if qn > 64 else 8
-    bn = 128 if tn > 64 else 8
+    bn = 128
     bd = 512 if d >= 512 else 128
 
     qp = _pad_to(_pad_to(q, 0, bq, 0), 1, bd, 0)

@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention import kernel as _k
 
 
@@ -15,8 +16,7 @@ def flash_attention_bshd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                          causal: bool = True,
                          interpret: bool | None = None) -> jnp.ndarray:
     """q: (B,S,H,dh); k/v: (B,T,HK,dh) -> (B,S,H,dh) (GQA: H % HK == 0)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     b, s, h, dh = q.shape
     _, t, hk, _ = k.shape
     group = h // hk

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import quantize as q
+from repro.kernels import interpret_mode
 from repro.kernels.hdc_encode import kernel as _k
 
 
@@ -19,8 +20,7 @@ def encode_quantize(x: jnp.ndarray, proj: jnp.ndarray, bits: int = 3,
     Pads every axis to block multiples; feature-dim padding contributes zero
     to both the matmul and the row norms, so results are exact.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     x = jnp.asarray(x, jnp.float32)
     proj = jnp.asarray(proj, jnp.float32)
     bsz, n = x.shape

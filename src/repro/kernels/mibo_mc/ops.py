@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import fefet, mibo
+from repro.kernels import interpret_mode
 from repro.kernels.mibo_mc import kernel as _k
 
 
@@ -22,8 +23,7 @@ def monte_carlo_ml_currents(key: jax.Array, stored: jnp.ndarray,
     this twice — once with query == stored (match leakage) and once with a
     single-cell mismatch (worst discharge) — and compare the distributions.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode(interpret)
     c = stored.shape[0]
     vth1, vth2 = mibo.stored_vths(stored, bits)
     g1, g2 = mibo.search_gate_voltages(query, bits)

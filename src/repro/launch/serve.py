@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.configs.registry import ALIASES, get_config
 from repro.core import hdc
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import transformer
 from repro.serve import AMService, IndexSpec
@@ -57,8 +58,8 @@ def parse_args(argv=None):
     ap.add_argument("--am-cache", type=int, default=8, metavar="CAPACITY",
                     help="AM response-cache capacity (0 disables the cache)")
     ap.add_argument("--am-sharded", action="store_true",
-                    help="route the AM cache through am.search_sharded on "
-                         "the serving mesh (rows banked over `model`)")
+                    help="route the AM cache through am.search_sharded, "
+                         "its rows banked over every device of the host")
     ap.add_argument("--am-merge",
                     choices=("auto", "allgather", "tree", "ring"),
                     default="auto",
@@ -135,7 +136,13 @@ def build_cache_service(args, mesh, *, start_driver=True):
 
 
 def main(argv=None):
+    """Serve ``--requests`` prompts; returns the cache service (or None).
+
+    The LM engine runs on one device.  With ``--am-sharded`` the cache
+    banks its rows over every device of the host.
+    """
     args = parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(ALIASES.get(args.arch, args.arch), smoke=args.smoke)
     mesh = make_test_mesh()
@@ -150,7 +157,9 @@ def main(argv=None):
             for _ in range(max(2, args.requests // 2))]
     workload = [pool[rng.integers(len(pool))] for _ in range(args.requests)]
 
-    svc = build_cache_service(args, mesh)
+    cache_mesh = (jax.make_mesh((jax.device_count(),), ("model",))
+                  if args.am_sharded else None)
+    svc = build_cache_service(args, cache_mesh)
     if svc is not None:
         proj = hdc.token_key_projection(cfg.vocab_size, CACHE_DIM)
         keys = [np.asarray(hdc.prompt_key(proj, p, CACHE_BITS))
@@ -231,6 +240,7 @@ def main(argv=None):
               f"{s['dedup_hits']} deduped ({s['dedup_rate']:.0%})")
         assert ts["rows"] <= ts["capacity"]
     assert len(results) == args.requests
+    return svc
 
 
 if __name__ == "__main__":

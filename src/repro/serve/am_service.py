@@ -163,6 +163,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
 from repro.core import am
 from repro.dist import specs as dist_specs
@@ -388,6 +389,34 @@ def _next_pow2(n: int) -> int:
     return 1 << max(0, n - 1).bit_length()
 
 
+def _auto_axes(mesh):
+    """``mesh`` with every axis Auto, whatever axis types it came with.
+
+    ``jax.make_mesh`` gives Explicit axes by default, which type-check
+    every update of a banked slab against the slab's sharding: an append of
+    m rows would have to divide evenly over the banks.  The service owns
+    its slabs' layout, so it runs on Auto axes, where the compiler places
+    a write of any row count.
+    """
+    auto = (jax.sharding.AxisType.Auto,) * len(mesh.axis_names)
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+
+
+@partial(jax.jit, static_argnames=("layout",))
+def _write_rows(slab, rows, start, *, layout=None):
+    """``slab`` with ``rows`` written from row ``start``.
+
+    ``layout`` (the slab's own sharding on a service mesh) pins the result's
+    layout, so a banked slab stays banked whatever the write's size; without
+    the pin the partitioner chooses the result's layout itself.
+    """
+    out = jax.lax.dynamic_update_slice(slab, rows.astype(slab.dtype),
+                                       (start, 0))
+    if layout is None:
+        return out
+    return jax.lax.with_sharding_constraint(out, layout)
+
+
 # ---------------------------------------------------------------------------
 # The service
 # ---------------------------------------------------------------------------
@@ -436,9 +465,9 @@ class AMService:
                 "frozen queue age).  Pass time_fn=time.monotonic and run "
                 "svc.start_driver() — or inject a fake clock in tests — "
                 "for a live idle deadline.", RuntimeWarning, stacklevel=2)
-        self._mesh = mesh
+        self._mesh = None if mesh is None else _auto_axes(mesh)
         self._merge = merge
-        self._rules = (rules or dist_specs.make_rules(mesh, "tp")) \
+        self._rules = (rules or dist_specs.make_rules(self._mesh, "tp")) \
             if mesh is not None else rules
         self.max_batch = max_batch
         self.flush_after = flush_after
@@ -569,10 +598,11 @@ class AMService:
                     f"backend {backend!r} lacks the 'masked' capability "
                     "tier required for ternary tables")
         table = am.make_table(
-            jnp.zeros((capacity, width), jnp.int32),
+            self._place(jnp.zeros((capacity, width), jnp.int32), rows=True),
             bits=bits, distance=distance,
-            meta=am.serving_meta(capacity, 0.0),
-            care_mask=(jnp.ones((capacity, width), jnp.int32)
+            meta=self._place(am.serving_meta(capacity, 0.0), rows=False),
+            care_mask=(self._place(jnp.ones((capacity, width), jnp.int32),
+                                   rows=True)
                        if ternary else None))
         if burst is None:
             burst = max(1.0, float(qps_budget)) if qps_budget else 1.0
@@ -585,6 +615,26 @@ class AMService:
                 qps_budget=qps_budget, burst=burst, max_queue=max_queue,
                 admission=admission, tokens=burst, tokens_at=self._now(),
                 index_spec=index)
+
+    def _place(self, slab, *, rows: bool):
+        """Lay a fresh capacity slab out on the service's mesh, if any.
+
+        Row slabs (codes, care) bank over ``rules.tp`` per
+        :meth:`Rules.am_table` when the capacity divides evenly over the
+        banks, so each device holds only its bank; meta, and row slabs that
+        do not divide, replicate.  Appends and compaction keep the layout.
+        """
+        if self._mesh is None:
+            return slab
+        banks = self._mesh.shape[self._rules.tp]
+        spec = (self._rules.am_table() if rows and slab.shape[0] % banks == 0
+                else self._rules.am_meta())
+        return jax.device_put(slab, NamedSharding(self._mesh, spec))
+
+    def _write(self, slab, rows, start: int):
+        """``slab`` with ``rows`` written from ``start``, in its own layout."""
+        layout = slab.sharding if self._mesh is not None else None
+        return _write_rows(slab, jnp.asarray(rows), start, layout=layout)
 
     def drop_table(self, name: str) -> None:
         """Remove a table; queued and in-flight lookups resolve first.
@@ -668,15 +718,11 @@ class AMService:
             start = t.n
             t.table = dataclasses.replace(
                 t.table,
-                codes=jax.lax.dynamic_update_slice(
-                    t.table.codes, jnp.asarray(codes), (t.n, 0)),
-                meta=jax.lax.dynamic_update_slice(
-                    t.table.meta, am.serving_meta(m, now), (t.n, 0)),
+                codes=self._write(t.table.codes, codes, t.n),
+                meta=self._write(t.table.meta, am.serving_meta(m, now), t.n),
                 care=(t.table.care if t.table.care is None else
-                      jax.lax.dynamic_update_slice(
-                          t.table.care,
-                          jnp.asarray((care != 0).astype(np.int32)),
-                          (t.n, 0))))
+                      self._write(t.table.care,
+                                  (care != 0).astype(np.int32), t.n)))
             t.values.extend(values)
             t.n += m
             t.appends += m
@@ -764,12 +810,10 @@ class AMService:
         keep = np.flatnonzero(~kill)
         t.table = dataclasses.replace(
             t.table,
-            codes=jnp.zeros_like(t.table.codes).at[:live.n_rows]
-                     .set(live.codes),
-            meta=jnp.zeros_like(t.table.meta).at[:live.n_rows].set(live.meta),
+            codes=self._write(jnp.zeros_like(t.table.codes), live.codes, 0),
+            meta=self._write(jnp.zeros_like(t.table.meta), live.meta, 0),
             care=(t.table.care if t.table.care is None else
-                  jnp.ones_like(t.table.care).at[:live.n_rows]
-                     .set(live.care)))
+                  self._write(jnp.ones_like(t.table.care), live.care, 0)))
         t.values = [t.values[i] for i in keep]
         t.n = live.n_rows
         t.version += 1
@@ -1163,16 +1207,10 @@ class AMService:
             tv = np.zeros((qb,), np.float32)
             tv[:q] = [fut.request.threshold for fut in uniq]
             thr = jnp.asarray(tv)
-        indexed = t.index is not None
+        args, kw = self._dispatch_args(t, queries, q, thr, now, k=k,
+                                       backend=backend, matches=matches)
         idx, dist, exact, matched, count, overflow, new_meta, frac = \
-            self._dispatch(
-                t.table, t.index, jnp.asarray(queries),
-                jnp.asarray(t.n, jnp.int32), jnp.asarray(q, jnp.int32), thr,
-                jnp.asarray(now, jnp.float32),
-                k=k, backend=backend, sharded=self._mesh is not None,
-                indexed=indexed,
-                probes=t.index_spec.probes if indexed else 0,
-                matches=matches)
+            self._dispatch(*args, **kw)
         g = _InFlightGroup(table=t, futs=futs, slot_of=slot_of,
                            arrays=(idx, dist, exact, matched, count,
                                    overflow),
@@ -1180,6 +1218,54 @@ class AMService:
                            values=t.values, now=now, index_frac=frac)
         self._in_flight.append(g)
         return g
+
+    def _dispatch_args(self, t: _TableState, queries, q: int, thr,
+                       now: float, *, k: int, backend: str,
+                       matches: int | None) -> tuple[tuple, dict]:
+        """Positional and static arguments of one :attr:`_dispatch` call."""
+        indexed = t.index is not None
+        args = (t.table, t.index, jnp.asarray(queries),
+                jnp.asarray(t.n, jnp.int32), jnp.asarray(q, jnp.int32), thr,
+                jnp.asarray(now, jnp.float32))
+        kw = dict(k=k, backend=backend, sharded=self._mesh is not None,
+                  indexed=indexed,
+                  probes=t.index_spec.probes if indexed else 0,
+                  matches=matches)
+        return args, kw
+
+    def lower(self, name: str, *, batch: int = 1, k: int = 1,
+              matches: int | None = None) -> jax.stages.Lowered:
+        """The dispatch a group of ``batch`` lookups on ``name`` would run.
+
+        Returned lowered, not run: ``.compile().as_text()`` shows what the
+        device executes (a Pallas kernel appears as ``tpu_custom_call``),
+        ``.compile().memory_analysis()`` its footprint.  ``k`` and
+        ``matches`` are :meth:`submit`'s; the lookups carry no threshold and
+        use the table's backend.
+        """
+        with self._lock:
+            t = self._state(name)
+            queries = np.zeros((_next_pow2(batch), t.table.width), np.int32)
+            args, kw = self._dispatch_args(
+                t, queries, batch, None, self._now(), k=min(k, t.capacity),
+                backend=t.backend, matches=matches)
+            return self._dispatch.lower(*args, **kw)
+
+    def live_table(self, name: str) -> am.AMTable:
+        """The live rows of ``name`` as an :class:`am.AMTable` snapshot.
+
+        Rows appear in the order lookups report them, so
+        ``am.search(svc.live_table(name), queries, k=k)`` answers what a
+        lookup against the service answers (for ``k`` up to the live row
+        count).
+        """
+        with self._lock:
+            t = self._state(name)
+            tb = t.table
+            return am.AMTable(
+                codes=tb.codes[:t.n], meta=tb.meta[:t.n],
+                care=None if tb.care is None else tb.care[:t.n],
+                bits=tb.bits, distance=tb.distance)
 
     def _complete_next(self, *, only_ready: bool = False) -> bool:
         """Retire the oldest in-flight group (FIFO); False if none retired.

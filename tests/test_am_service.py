@@ -517,6 +517,38 @@ def test_sharded_placement_matches_local():
         raise AssertionError("AMService accepted an unknown merge strategy")
 
 
+def test_default_mesh_appends_past_capacity_stay_banked():
+    """``jax.make_mesh`` with its default (Explicit) axis types: appends of
+    any row count and the LRU compaction past capacity keep the slab banked
+    over ``model``, and lookups stay bitwise equal to a local service and
+    to the ``ref`` search of the live rows."""
+    mesh = jax.make_mesh((len(jax.devices()),), ("model",))
+    rng = np.random.default_rng(21)
+    local, sharded = AMService(), AMService(mesh=mesh)
+    for svc in (local, sharded):
+        svc.create_table("t", width=8, bits=3, capacity=24, policy="lru",
+                         backend="pallas")
+    for m in (5, 7, 3, 9, 11):                     # 35 rows into 24 slots
+        codes = _codes(rng, m, width=8)
+        local.append("t", codes)
+        sharded.append("t", codes)
+    slab = sharded._tables["t"].table.codes
+    assert slab.sharding.spec[0] == "model" and not slab.is_fully_replicated
+    assert sharded.stats("t")["rows"] == 24
+    live = sharded.live_table("t")
+    queries = np.concatenate([np.asarray(live.codes[::5]),
+                              _codes(rng, 4, width=8)])
+    want = am.search(live, queries, k=4, backend="ref")
+    fl = [local.submit("t", q, k=4) for q in queries]
+    fs = [sharded.submit("t", q, k=4) for q in queries]
+    for i, (a, b) in enumerate(zip(fl, fs)):
+        ra, rb = a.result(), b.result()
+        np.testing.assert_array_equal(rb.indices, want.indices[i])
+        np.testing.assert_array_equal(rb.distances, want.distances[i])
+        np.testing.assert_array_equal(ra.indices, rb.indices)
+        np.testing.assert_array_equal(ra.distances, rb.distances)
+
+
 def test_next_pow2():
     assert [_next_pow2(n) for n in (1, 2, 3, 4, 5, 63, 64, 65)] == \
         [1, 2, 4, 4, 8, 64, 64, 128]

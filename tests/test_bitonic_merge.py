@@ -2,9 +2,10 @@
 
 The bitonic network (``kernel._bitonic_topk_merge``) replaced the sequential
 argmin selection (``kernel._topk_merge``) as the fused kernel's per-block
-fold — O(log^2(k + bn)) compare-exchange stages instead of O(k * (k + bn))
-vector ops — which is what lifted ``am.FUSED_K_MAX`` from 64 to 256.  The
-two networks must agree **bitwise** on every input the kernel can feed them:
+fold — O(log^2 bn + log k) compare-exchange stages instead of
+O(k * (k + bn)) vector ops — which is what lifted ``am.FUSED_K_MAX`` from 64
+to 256.  The two networks must agree **bitwise** on every input the kernel
+can feed them:
 
 * the unit itself, vs the argmin merge as oracle AND vs a plain numpy
   lexsort, over random/tie-heavy/degenerate states — including all-+inf
@@ -20,6 +21,9 @@ is lexicographically sorted by (distance, row index) with **distinct** real
 row indices (rows arrive from disjoint table blocks; only the +inf/_NO_ROW
 sentinel pair may repeat).  The argmin oracle dedups equal (d, i) pairs, so
 feeding it duplicate real rows — impossible in the kernel — would diverge.
+
+The network's lane rotations (``pltpu.roll``) have no eager rule, so the
+unit runs under ``jax.jit`` here, outside any kernel.
 """
 
 import jax
@@ -34,6 +38,8 @@ from repro.kernels.cam_search import ops as cam_ops
 from repro.kernels.cam_search import ref as cam_ref
 
 _NO_ROW = np.iinfo(np.int32).max
+
+_bitonic = jax.jit(cam_k._bitonic_topk_merge, static_argnums=4)
 
 
 def _running_best(rng, bq, k, *, inf_frac=0.3, sentinel_frac=0.5):
@@ -89,7 +95,7 @@ def test_bitonic_matches_argmin_and_numpy(bq, k, bn, seed):
     cand_d, cand_i = _candidates(rng, bq, bn)
     args = (jnp.asarray(best_d), jnp.asarray(best_i),
             jnp.asarray(cand_d), jnp.asarray(cand_i))
-    got = cam_k._bitonic_topk_merge(*args, k)
+    got = _bitonic(*args, k)
     _assert_same(got, cam_k._topk_merge(*args, k), "vs argmin")
     _assert_same(got, _numpy_merge(best_d, best_i, cand_d, cand_i, k),
                  "vs numpy")
@@ -112,7 +118,7 @@ def test_bitonic_tie_heavy_binary(bq, k, bn, seed):
                              (bq, bn)).copy()
     args = (jnp.asarray(best_d), jnp.asarray(best_i),
             jnp.asarray(cand_d), jnp.asarray(cand_i))
-    got = cam_k._bitonic_topk_merge(*args, k)
+    got = _bitonic(*args, k)
     _assert_same(got, cam_k._topk_merge(*args, k), "vs argmin")
     _assert_same(got, _numpy_merge(best_d, best_i, cand_d, cand_i, k),
                  "vs numpy")
@@ -127,12 +133,12 @@ def test_bitonic_all_inf_unfilled_state():
     cand_d, cand_i = _candidates(rng, bq, bn)
     args = (jnp.asarray(best_d), jnp.asarray(best_i),
             jnp.asarray(cand_d), jnp.asarray(cand_i))
-    got = cam_k._bitonic_topk_merge(*args, k)
+    got = _bitonic(*args, k)
     _assert_same(got, cam_k._topk_merge(*args, k))
     # and an all-+inf candidate block leaves the state unchanged
     cand_d = np.full((bq, bn), np.inf, np.float32)
     best_d, best_i = _running_best(rng, bq, k)
-    got = cam_k._bitonic_topk_merge(
+    got = _bitonic(
         jnp.asarray(best_d), jnp.asarray(best_i), jnp.asarray(cand_d),
         jnp.full((bq, bn), _NO_ROW, jnp.int32), k)
     _assert_same(got, (best_d, best_i))
@@ -147,22 +153,33 @@ def test_bitonic_degenerate_shapes(k, bn):
     cand_d, cand_i = _candidates(rng, 2, bn)
     args = (jnp.asarray(best_d), jnp.asarray(best_i),
             jnp.asarray(cand_d), jnp.asarray(cand_i))
-    got = cam_k._bitonic_topk_merge(*args, k)
+    got = _bitonic(*args, k)
     _assert_same(got, cam_k._topk_merge(*args, k))
 
 
-def test_bitonic_is_min_max_only():
-    """The network must stay VPU-lowerable: no sort/top_k primitives in its
-    jaxpr, only the select/min/max family the compare-exchange builds on."""
+@pytest.mark.parametrize("k,bn", [(10, 128), (100, 128), (256, 128)])
+def test_bitonic_is_rotations_and_selects(k, bn):
+    """The network Mosaic lowers: no sort/top_k, no reversal, no reshape.
+
+    Each compare-exchange step is two lane rotations per key (partner at
+    ``x + j`` and ``x - j``), so the rotation count is the stage count of
+    the network: a full sort of the bn candidates, then one merge of the
+    running list's power-of-two width.
+    """
     rng = np.random.default_rng(1)
-    best_d, best_i = _running_best(rng, 2, 16)
-    cand_d, cand_i = _candidates(rng, 2, 32)
+    best_d, best_i = _running_best(rng, 8, k)
+    cand_d, cand_i = _candidates(rng, 8, bn)
+    w = cam_k._bitonic_width(k, bn)
     jaxpr = jax.make_jaxpr(
-        lambda a, b, c, d: cam_k._bitonic_topk_merge(a, b, c, d, 16))(
+        lambda a, b, c, d: cam_k._bitonic_topk_merge(a, b, c, d, k))(
             jnp.asarray(best_d), jnp.asarray(best_i),
             jnp.asarray(cand_d), jnp.asarray(cand_i))
-    prims = {eqn.primitive.name for eqn in jaxpr.jaxpr.eqns}
-    assert "sort" not in prims and "top_k" not in prims, prims
+    prims = [eqn.primitive.name for eqn in jaxpr.jaxpr.eqns]
+    assert not {"sort", "top_k", "rev", "reshape", "gather"} & set(prims), (
+        sorted(set(prims)))
+    log_bn, log_w = bn.bit_length() - 1, w.bit_length() - 1
+    stages = log_bn * (log_bn + 1) // 2 + log_w
+    assert prims.count("roll") == 4 * stages
 
 
 # ---------------------------------------------------------------------------

@@ -168,3 +168,20 @@ def test_mibo_mc_margin_separation():
     assert float(jnp.percentile(i_mm, 1.0)) > 3 * float(
         jnp.percentile(i_match, 99.0))
     assert float(jnp.min(i_mm)) > float(jnp.max(i_match))
+
+
+# ---------------------------------------------------------------------------
+# backend selection: compiled on the TPU, interpreted on the CPU, else refused
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, want):
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert interpret_mode(True) is True and interpret_mode(False) is False
+    if want is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            interpret_mode(None)
+    else:
+        assert interpret_mode(None) is want
