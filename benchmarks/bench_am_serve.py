@@ -134,6 +134,16 @@ def _run_waves(svc, codes, workload, names, batch, waves, *,
     return time.perf_counter() - t0
 
 
+def _queue_wait(svc, since=None):
+    """The service's queue-wait counters ``(seconds, lookups)``; with
+    ``since`` (an earlier reading), the mean wait in seconds between the
+    two readings."""
+    now = (svc.stats()["queue_wait_s"], svc.dispatched)
+    if since is None:
+        return now
+    return (now[0] - since[0]) / max(1, now[1] - since[1])
+
+
 def run_saturation(smoke: bool = False, *, dim: int = 64,
                    population: int = 256, batch: int = 32,
                    waves: int = 48, backend: str = "ref",
@@ -192,21 +202,20 @@ def run_saturation(smoke: bool = False, *, dim: int = 64,
         # synchronous reference: launch + readback serial per wave
         svc, names = mk(n_tables)
         _run_waves(svc, codes, workload, names, batch, waves, sync=True)
-        svc._wait_samples.clear()     # drop warmup waits from the p99
+        warm = _queue_wait(svc)       # warmup waits stay out of the mean
         sync_s = _run_waves(svc, codes, workload, names, batch, waves,
                             sync=True)
-        sync_p99 = svc.stats()["queue_wait_p99"]
+        sync_wait = _queue_wait(svc, since=warm)
 
         # pipelined: background driver, dispatch overlapped with readback
         svc, names = mk(n_tables)
         _run_waves(svc, codes, workload, names, batch, waves, sync=True)
-        svc._wait_samples.clear()
+        warm = _queue_wait(svc)
         svc.start_driver(max_in_flight=4)
         try:
             async_s = _run_waves(svc, codes, workload, names, batch, waves,
                                  sync=False)
-            stats = svc.stats()
-            async_p99 = stats["queue_wait_p99"]
+            async_wait = _queue_wait(svc, since=warm)
         finally:
             svc.stop_driver()
         n_req = waves * batch
@@ -215,8 +224,8 @@ def run_saturation(smoke: bool = False, *, dim: int = 64,
              1e6 * async_s / n_req,
              f"sync_us_per_lookup={1e6 * sync_s / n_req:.1f};"
              f"async_over_sync_throughput={sync_s / async_s:.2f};"
-             f"sync_p99_us={1e6 * sync_p99:.0f};"
-             f"async_p99_us={1e6 * async_p99:.0f};"
+             f"sync_wait_us={1e6 * sync_wait:.0f};"
+             f"async_wait_us={1e6 * async_wait:.0f};"
              f"device_frac={device_frac:.2f};"
              f"in_flight_cap=4")
         # the pipeline must not cost meaningful throughput even when the

@@ -56,7 +56,7 @@ Design — why this never compiles or syncs per request:
   :class:`AdmissionError`, ``"shed"`` resolves the lookup immediately as a
   non-admitted miss (``SearchResponse.admitted`` False), ``"block"`` waits
   for headroom.  Counters surface through ``stats()`` (queue depth,
-  in-flight groups, rejected/shed/blocked, p50/p99 queue wait).
+  in-flight groups, rejected/shed/blocked, cumulative queue wait).
 * **Cross-request dedup.**  Identical (query, threshold) rows inside one
   flush group are dispatched once and the shared result row fans out to
   every duplicate — under Zipfian traffic most of a wave is repeats, so
@@ -148,6 +148,23 @@ Latency control: ``max_batch`` caps how many lookups queue before an
 automatic dispatch, and ``flush_after`` is a deadline (in clock units) on
 the oldest queued request — enforced at every submit, by the driver's loop,
 and by the legacy :meth:`AMService.poll` hook for loops that poll by hand.
+
+Profiler spans: the service's stages run inside ``jax.profiler``
+``TraceAnnotation`` spans, on the clock the device trace uses, so a trace
+shows which stage the host was in while the device idled.  With no
+profiler running a span only checks that none is.
+
+* ``am.append`` — the public :meth:`AMService.append` under the lock
+  (``table``, ``rows``);
+* ``am.make_room`` — eviction before an append: meta readback, policy, any
+  compaction (``rows``: live rows before);
+* ``am.write`` — each slab write (``slab``: codes, meta or care);
+* ``am.launch`` — one group's dedup, padding and dispatch enqueue
+  (``group``: the service's sequence number of the group, ``lookups``,
+  ``bucket``);
+* ``am.resolve`` — one group's completion stage, and inside it
+  ``am.readback`` around ``jax.device_get`` (``group``);
+* ``am.driver.wait`` — the background driver idle between steps.
 """
 
 from __future__ import annotations
@@ -163,6 +180,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding
 
 from repro.core import am
@@ -190,9 +208,6 @@ COMPLETION_ORDER = "fifo"
 #: logical clock rebases every live timestamp down once it reaches this, so
 #: LRU/TTL ordering stays exact for arbitrarily long-running services.
 _REBASE_TICKS = float(1 << 23)
-
-#: Resolved queue-wait samples kept for the stats() percentiles.
-_WAIT_SAMPLES = 4096
 
 
 class TableFullError(RuntimeError):
@@ -376,6 +391,7 @@ class _InFlightGroup:
     version: int                   # table.version at launch
     values: list                   # payload list as of launch
     now: float                     # dispatch-time clock reading
+    seq: int                       # the service's sequence number of it
     index_frac: Any = None         # device scalar: mean candidate fraction
     #                                (None when the dispatch was unindexed)
 
@@ -480,8 +496,6 @@ class AMService:
         self._pending: list[PendingSearch] = []
         self._in_flight: collections.deque[_InFlightGroup] = \
             collections.deque()
-        self._wait_samples: collections.deque[float] = \
-            collections.deque(maxlen=_WAIT_SAMPLES)
         self._drain_req = False
         self._resolving = 0            # popped in-flight groups mid-readback
         self._driver: AMDriver | None = None
@@ -489,6 +503,8 @@ class AMService:
         self.flushes = 0
         self.readbacks = 0
         self.dispatched = 0            # requests routed through a dispatch
+        self.queue_wait_s = 0.0        # of those, summed launch - submit
+        self._group_seq = 0            # dispatch groups launched
         self.dedup_hits = 0            # of those, resolved from a shared row
         self.fused_fallbacks = 0       # groups dense-downgraded by k ceiling
         self._dispatch = self._build_dispatch()
@@ -631,10 +647,12 @@ class AMService:
                 else self._rules.am_meta())
         return jax.device_put(slab, NamedSharding(self._mesh, spec))
 
-    def _write(self, slab, rows, start: int):
-        """``slab`` with ``rows`` written from ``start``, in its own layout."""
-        layout = slab.sharding if self._mesh is not None else None
-        return _write_rows(slab, jnp.asarray(rows), start, layout=layout)
+    def _write(self, slab, rows, start: int, *, name: str):
+        """``slab`` (named ``name``) with ``rows`` written from ``start``, in
+        its own layout."""
+        with TraceAnnotation("am.write", slab=name):
+            layout = slab.sharding if self._mesh is not None else None
+            return _write_rows(slab, jnp.asarray(rows), start, layout=layout)
 
     def drop_table(self, name: str) -> None:
         """Remove a table; queued and in-flight lookups resolve first.
@@ -686,7 +704,8 @@ class AMService:
         codes = np.asarray(codes, np.int32)
         if codes.ndim == 1:
             codes = codes[None]
-        with self._lock:
+        with self._lock, TraceAnnotation("am.append", table=name,
+                                         rows=codes.shape[0]):
             t = self._state(name)
             if codes.ndim != 2 or codes.shape[1] != t.table.width:
                 raise ValueError(f"append codes shape {codes.shape} != "
@@ -718,11 +737,13 @@ class AMService:
             start = t.n
             t.table = dataclasses.replace(
                 t.table,
-                codes=self._write(t.table.codes, codes, t.n),
-                meta=self._write(t.table.meta, am.serving_meta(m, now), t.n),
+                codes=self._write(t.table.codes, codes, t.n, name="codes"),
+                meta=self._write(t.table.meta, am.serving_meta(m, now), t.n,
+                                 name="meta"),
                 care=(t.table.care if t.table.care is None else
                       self._write(t.table.care,
-                                  (care != 0).astype(np.int32), t.n)))
+                                  (care != 0).astype(np.int32), t.n,
+                                  name="care")))
             t.values.extend(values)
             t.n += m
             t.appends += m
@@ -779,26 +800,29 @@ class AMService:
 
     def _make_room(self, t: _TableState, m: int, now: float) -> None:
         """Evict per policy so ``m`` more rows fit under ``capacity``."""
-        if t.n == 0:
-            return
-        kill = np.zeros((t.n,), bool)
-        meta = np.asarray(t.table.meta[:t.n])
-        if t.policy == "ttl":
-            kill |= (now - meta[:, am.META_INSERT]) > t.ttl
-        overflow = (t.n - int(kill.sum())) + m - t.capacity
-        if overflow > 0:
-            if t.policy == "reject":
-                raise TableFullError(
-                    f"table {t.name!r} is full ({t.capacity} rows) and "
-                    f"policy 'reject' forbids eviction")
-            # lru: least-recently-hit first; ttl overflow: oldest insert first
-            col = am.META_LAST_HIT if t.policy == "lru" else am.META_INSERT
-            alive = np.flatnonzero(~kill)
-            order = alive[np.argsort(meta[alive, col], kind="stable")]
-            kill[order[:overflow]] = True
-        if kill.any():
-            t.evicted += int(kill.sum())
-            self._compact(t, kill)
+        with TraceAnnotation("am.make_room", rows=t.n):
+            if t.n == 0:
+                return
+            kill = np.zeros((t.n,), bool)
+            meta = np.asarray(t.table.meta[:t.n])
+            if t.policy == "ttl":
+                kill |= (now - meta[:, am.META_INSERT]) > t.ttl
+            overflow = (t.n - int(kill.sum())) + m - t.capacity
+            if overflow > 0:
+                if t.policy == "reject":
+                    raise TableFullError(
+                        f"table {t.name!r} is full ({t.capacity} rows) and "
+                        f"policy 'reject' forbids eviction")
+                # lru: least-recently-hit first; ttl overflow: oldest
+                # insert first
+                col = (am.META_LAST_HIT if t.policy == "lru"
+                       else am.META_INSERT)
+                alive = np.flatnonzero(~kill)
+                order = alive[np.argsort(meta[alive, col], kind="stable")]
+                kill[order[:overflow]] = True
+            if kill.any():
+                t.evicted += int(kill.sum())
+                self._compact(t, kill)
 
     def _compact(self, t: _TableState, kill: np.ndarray) -> None:
         """Delete masked live rows and repack survivors at the slab front."""
@@ -810,10 +834,13 @@ class AMService:
         keep = np.flatnonzero(~kill)
         t.table = dataclasses.replace(
             t.table,
-            codes=self._write(jnp.zeros_like(t.table.codes), live.codes, 0),
-            meta=self._write(jnp.zeros_like(t.table.meta), live.meta, 0),
+            codes=self._write(jnp.zeros_like(t.table.codes), live.codes, 0,
+                              name="codes"),
+            meta=self._write(jnp.zeros_like(t.table.meta), live.meta, 0,
+                             name="meta"),
             care=(t.table.care if t.table.care is None else
-                  self._write(jnp.ones_like(t.table.care), live.care, 0)))
+                  self._write(jnp.ones_like(t.table.care), live.care, 0,
+                              name="care")))
         t.values = [t.values[i] for i in keep]
         t.n = live.n_rows
         t.version += 1
@@ -1174,50 +1201,58 @@ class AMService:
         completion.  Hashing happens BEFORE padding, so a wave of repeats
         can collapse into a smaller power-of-two bucket.
         """
-        slot_of: list[int] = []
-        slots: dict[tuple[bytes, float | None], int] = {}
-        uniq: list[PendingSearch] = []
-        for fut in futs:
-            r = fut.request
-            key = (r.query.tobytes(), r.threshold)
-            slot = slots.setdefault(key, len(slots))
-            if slot == len(uniq):
-                uniq.append(fut)
-            slot_of.append(slot)
-        q = len(uniq)
-        self.dispatched += len(futs)
-        self.dedup_hits += len(futs) - q
-        # Host-side mirror of am.fused_fallbacks(): the compiled dispatch
-        # silently takes the dense O(Q*N) path when the request's window
-        # exceeds am.FUSED_K_MAX even though the backend has a fused tier.
-        # The trace-time counter in am only ticks once per compile; this one
-        # ticks per launched group, so saturation is visible in stats().
-        be = am._resolve_backend(t.backend)
-        k_eff = min(matches if matches is not None else k,
-                    t.table.n_rows)
-        if (be.fused is not None and k_eff > am.FUSED_K_MAX
-                and (matches is None or be.fused_count)):
-            self.fused_fallbacks += 1
-        qb = _next_pow2(q)
-        queries = np.zeros((qb, t.table.width), np.int32)
-        for i, fut in enumerate(uniq):
-            queries[i] = fut.request.query
-        thr = None
-        if has_thr:
-            tv = np.zeros((qb,), np.float32)
-            tv[:q] = [fut.request.threshold for fut in uniq]
-            thr = jnp.asarray(tv)
-        args, kw = self._dispatch_args(t, queries, q, thr, now, k=k,
-                                       backend=backend, matches=matches)
-        idx, dist, exact, matched, count, overflow, new_meta, frac = \
-            self._dispatch(*args, **kw)
-        g = _InFlightGroup(table=t, futs=futs, slot_of=slot_of,
-                           arrays=(idx, dist, exact, matched, count,
-                                   overflow),
-                           new_meta=new_meta, version=t.version,
-                           values=t.values, now=now, index_frac=frac)
-        self._in_flight.append(g)
-        return g
+        seq, self._group_seq = self._group_seq, self._group_seq + 1
+        with TraceAnnotation("am.launch", group=seq,
+                             lookups=len(futs)) as span:
+            slot_of: list[int] = []
+            slots: dict[tuple[bytes, float | None], int] = {}
+            uniq: list[PendingSearch] = []
+            for fut in futs:
+                r = fut.request
+                key = (r.query.tobytes(), r.threshold)
+                slot = slots.setdefault(key, len(slots))
+                if slot == len(uniq):
+                    uniq.append(fut)
+                slot_of.append(slot)
+            q = len(uniq)
+            self.dispatched += len(futs)
+            self.queue_wait_s += sum(now - fut.request.submitted_at
+                                     for fut in futs)
+            self.dedup_hits += len(futs) - q
+            # Host-side mirror of am.fused_fallbacks(): the compiled
+            # dispatch silently takes the dense O(Q*N) path when the
+            # request's window exceeds am.FUSED_K_MAX even though the
+            # backend has a fused tier.  The trace-time counter in am only
+            # ticks once per compile; this one ticks per launched group, so
+            # saturation is visible in stats().
+            be = am._resolve_backend(t.backend)
+            k_eff = min(matches if matches is not None else k,
+                        t.table.n_rows)
+            if (be.fused is not None and k_eff > am.FUSED_K_MAX
+                    and (matches is None or be.fused_count)):
+                self.fused_fallbacks += 1
+            qb = _next_pow2(q)
+            span.set_metadata(bucket=qb)
+            queries = np.zeros((qb, t.table.width), np.int32)
+            for i, fut in enumerate(uniq):
+                queries[i] = fut.request.query
+            thr = None
+            if has_thr:
+                tv = np.zeros((qb,), np.float32)
+                tv[:q] = [fut.request.threshold for fut in uniq]
+                thr = jnp.asarray(tv)
+            args, kw = self._dispatch_args(t, queries, q, thr, now, k=k,
+                                           backend=backend, matches=matches)
+            idx, dist, exact, matched, count, overflow, new_meta, frac = \
+                self._dispatch(*args, **kw)
+            g = _InFlightGroup(table=t, futs=futs, slot_of=slot_of,
+                               arrays=(idx, dist, exact, matched, count,
+                                       overflow),
+                               new_meta=new_meta, version=t.version,
+                               values=t.values, now=now, seq=seq,
+                               index_frac=frac)
+            self._in_flight.append(g)
+            return g
 
     def _dispatch_args(self, t: _TableState, queries, q: int, thr,
                        now: float, *, k: int, backend: str,
@@ -1284,7 +1319,8 @@ class AMService:
             self._in_flight.popleft()
             self._resolving += 1
         try:
-            self._resolve_group(g)
+            with TraceAnnotation("am.resolve", group=g.seq):
+                self._resolve_group(g)
         finally:
             with self._cv:
                 self._resolving -= 1
@@ -1300,8 +1336,9 @@ class AMService:
         the table version is unchanged since launch — a racing append or
         eviction wins and the stale touch is dropped.
         """
-        (idx, dist, exact, matched, count, overflow), frac = jax.device_get(
-            (g.arrays, g.index_frac))
+        with TraceAnnotation("am.readback", group=g.seq):
+            (idx, dist, exact, matched, count, overflow), frac = \
+                jax.device_get((g.arrays, g.index_frac))
         with self._cv:
             t = g.table
             if self._tables.get(t.name) is t and t.version == g.version:
@@ -1311,7 +1348,6 @@ class AMService:
                 t.index_groups += 1
                 t.index_frac_sum += float(frac)
             self.readbacks += 1
-            done_at = self._now()
             for fut, slot in zip(g.futs, g.slot_of):
                 hit = bool(exact[slot, 0])
                 if hit:
@@ -1327,8 +1363,6 @@ class AMService:
                                  else int(count[slot])),
                     overflow=(None if overflow is None
                               else bool(overflow[slot]))))
-                self._wait_samples.append(
-                    done_at - fut.request.submitted_at)
             self._cv.notify_all()
 
     # -- driver lifecycle ----------------------------------------------------
@@ -1450,9 +1484,12 @@ class AMService:
     def stats(self, name: str | None = None) -> dict:
         """Service-level (or one table's) observability counters.
 
-        Queue-wait percentiles are over the last ``_WAIT_SAMPLES`` resolved
-        lookups, in clock units (seconds under a wall clock, ticks under
-        the logical one).
+        ``queue_wait_s`` is cumulative: the sum, over every lookup launched
+        (deduplicated repeats included, as in ``dispatched``), of its
+        launch time minus its submit time, in clock units (seconds under a
+        wall clock, ticks under the logical one).  Its increase over the
+        increase of :attr:`dispatched` across any window is that window's
+        mean queue wait.
         """
         with self._lock:
             if name is not None:
@@ -1479,9 +1516,6 @@ class AMService:
                     },
                 }
             cache_size = getattr(self._dispatch, "_cache_size", None)
-            waits = np.asarray(self._wait_samples, np.float64)
-            p50, p99 = (np.percentile(waits, [50, 99]) if waits.size
-                        else (0.0, 0.0))
             drv = self._driver
             return {
                 "tables": {n: self.stats(n) for n in self._tables},
@@ -1518,8 +1552,7 @@ class AMService:
                         / max(1, sum(t.index_groups
                                      for t in self._tables.values())),
                 },
-                "queue_wait_p50": float(p50),
-                "queue_wait_p99": float(p99),
+                "queue_wait_s": self.queue_wait_s,
             }
 
 
@@ -1635,7 +1668,8 @@ class AMDriver:
             while not self._stop_evt.is_set():
                 r = self.run_once()
                 if not r["launched"] and not r["completed"]:
-                    self._wake.wait(self.poll_interval)
+                    with TraceAnnotation("am.driver.wait"):
+                        self._wake.wait(self.poll_interval)
                     self._wake.clear()
         except BaseException as e:               # pragma: no cover - safety
             self.exception = e

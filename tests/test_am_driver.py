@@ -351,7 +351,8 @@ def test_background_driver_end_to_end():
         assert svc.drain(timeout=5.0)
         s = svc.stats()
         assert s["pending"] == 0 and s["in_flight"] == 0
-        assert s["queue_wait_p99"] >= s["queue_wait_p50"] >= 0.0
+        # every lookup launched waited a real, non-negative time
+        assert svc.dispatched == 12 and s["queue_wait_s"] >= 0.0
     finally:
         svc.stop_driver()
 
@@ -367,7 +368,8 @@ def test_stats_surface_queue_and_wait_percentiles():
     svc.flush()
     s = svc.stats()
     assert s["queue_depth"] == 0
-    assert s["queue_wait_p50"] >= 0.0
+    # the logical clock ticked once between the submit and the flush
+    assert s["queue_wait_s"] == 1.0
 
 
 # ---------------------------------------------------------------------------
