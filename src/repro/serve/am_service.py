@@ -156,8 +156,11 @@ profiler running a span only checks that none is.
 
 * ``am.append`` — the public :meth:`AMService.append` under the lock
   (``table``, ``rows``);
-* ``am.make_room`` — eviction before an append: meta readback, policy, any
-  compaction (``rows``: live rows before);
+* ``am.make_room`` — eviction before an append: the policy, any
+  compaction, and a readback of the policy's meta column only where the
+  policy can evict, ``ttl`` always and ``lru`` at capacity; ``reject`` and
+  ``lru`` below capacity decide from the fill alone (``rows``: live rows
+  before);
 * ``am.write`` — each slab write (``slab``: codes, meta or care);
 * ``am.launch`` — one group's dedup, padding and dispatch enqueue
   (``group``: the service's sequence number of the group, ``lookups``,
@@ -348,6 +351,7 @@ class _TableState:
     version: int = 0               # bumped on every append/delete/evict
     appends: int = 0
     evicted: int = 0
+    meta_readbacks: int = 0        # _make_room calls that read meta back
     hits: int = 0
     misses: int = 0
     # -- admission control ---------------------------------------------------
@@ -431,6 +435,17 @@ def _write_rows(slab, rows, start, *, layout=None):
     if layout is None:
         return out
     return jax.lax.with_sharding_constraint(out, layout)
+
+
+@partial(jax.jit, static_argnames=("col",))
+def _meta_column(meta, *, col):
+    """Column ``col`` of the whole ``(capacity, 2)`` meta slab.
+
+    Its shape is the slab's, never the live row count's, so it compiles
+    once per capacity and layout; the caller slices the live rows on the
+    host.
+    """
+    return meta[:, col]
 
 
 # ---------------------------------------------------------------------------
@@ -799,26 +814,34 @@ class AMService:
             return before - t.n
 
     def _make_room(self, t: _TableState, m: int, now: float) -> None:
-        """Evict per policy so ``m`` more rows fit under ``capacity``."""
+        """Evict per policy so ``m`` more rows fit under ``capacity``.
+
+        Only ``ttl`` (expiry, always) and ``lru`` at capacity read meta
+        back, and then only the one column the policy orders by; ``reject``
+        and ``lru`` below capacity decide from the fill alone.
+        """
         with TraceAnnotation("am.make_room", rows=t.n):
             if t.n == 0:
                 return
+            overflow = t.n + m - t.capacity
+            if t.policy != "ttl" and overflow <= 0:
+                return
+            if t.policy == "reject":
+                raise TableFullError(
+                    f"table {t.name!r} is full ({t.capacity} rows) and "
+                    f"policy 'reject' forbids eviction")
+            # lru: least-recently-hit first; ttl: expiry by insert age,
+            # then oldest insert first on overflow
+            col = am.META_LAST_HIT if t.policy == "lru" else am.META_INSERT
+            stamps = np.asarray(_meta_column(t.table.meta, col=col))[:t.n]
+            t.meta_readbacks += 1
             kill = np.zeros((t.n,), bool)
-            meta = np.asarray(t.table.meta[:t.n])
             if t.policy == "ttl":
-                kill |= (now - meta[:, am.META_INSERT]) > t.ttl
-            overflow = (t.n - int(kill.sum())) + m - t.capacity
+                kill |= (now - stamps) > t.ttl
+                overflow -= int(kill.sum())
             if overflow > 0:
-                if t.policy == "reject":
-                    raise TableFullError(
-                        f"table {t.name!r} is full ({t.capacity} rows) and "
-                        f"policy 'reject' forbids eviction")
-                # lru: least-recently-hit first; ttl overflow: oldest
-                # insert first
-                col = (am.META_LAST_HIT if t.policy == "lru"
-                       else am.META_INSERT)
                 alive = np.flatnonzero(~kill)
-                order = alive[np.argsort(meta[alive, col], kind="stable")]
+                order = alive[np.argsort(stamps[alive], kind="stable")]
                 kill[order[:overflow]] = True
             if kill.any():
                 t.evicted += int(kill.sum())
@@ -1490,6 +1513,11 @@ class AMService:
         wall clock, ticks under the logical one).  Its increase over the
         increase of :attr:`dispatched` across any window is that window's
         mean queue wait.
+
+        ``meta_readbacks`` (per table, and summed over tables) counts the
+        appends and evicts whose eviction step read the table's meta back:
+        every one on a ``ttl`` table that holds rows, those at capacity on
+        an ``lru`` table, none on a ``reject`` table.
         """
         with self._lock:
             if name is not None:
@@ -1498,6 +1526,7 @@ class AMService:
                     "rows": t.n, "capacity": t.capacity, "policy": t.policy,
                     "ttl": t.ttl, "backend": t.backend, "version": t.version,
                     "appends": t.appends, "evicted": t.evicted,
+                    "meta_readbacks": t.meta_readbacks,
                     "hits": t.hits, "misses": t.misses,
                     "lookups": t.hits + t.misses,
                     "queued": t.queued,
@@ -1553,6 +1582,8 @@ class AMService:
                                      for t in self._tables.values())),
                 },
                 "queue_wait_s": self.queue_wait_s,
+                "meta_readbacks": sum(t.meta_readbacks
+                                      for t in self._tables.values()),
             }
 
 

@@ -16,7 +16,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import am
 from repro.serve.am_service import (AMService, SearchResponse,
-                                    TableFullError, _next_pow2)
+                                    TableFullError, _meta_column, _next_pow2)
 
 WIDTH = 6
 
@@ -459,6 +459,90 @@ def test_reject_policy_raises_instead_of_evicting():
     assert svc.stats("t")["rows"] == 2
 
 
+def _evict_reference(meta, policy, ttl, now, m, capacity):
+    """The rows an append of ``m`` evicts, in the order the policy picks
+    them: expired rows (``ttl``) by row, then the overflow by last hit
+    (``lru``) or insert time (``ttl``), ties by row."""
+    kill = np.zeros(len(meta), bool)
+    if policy == "ttl":
+        kill = (now - meta[:, am.META_INSERT]) > ttl
+    picked = list(np.flatnonzero(kill))
+    overflow = len(meta) - kill.sum() + m - capacity
+    if overflow > 0 and policy != "reject":
+        col = am.META_LAST_HIT if policy == "lru" else am.META_INSERT
+        alive = np.flatnonzero(~kill)
+        picked += list(alive[np.argsort(meta[alive, col],
+                                        kind="stable")][:overflow])
+    return [int(i) for i in picked]
+
+
+#: Rows a final append (at clock 5.0) of ``m`` rows evicts from the table
+#: ``_eviction_scenario`` builds: rows 0-2 inserted at 1.0, 3-4 at 2.0, 5 at
+#: 4.0; rows 0 and 4 hit at 4.5 and 4.75; capacity 8, ttl 3.5.
+EVICTION_CASES = {
+    # (policy, fill): (m, rows evicted in pick order, meta readbacks)
+    ("reject", "below"): (2, [], 0),
+    ("reject", "at"): (3, None, 0),                # TableFullError
+    ("lru", "below"): (2, [], 0),
+    ("lru", "at"): (3, [1], 1),                    # last hit 1 ties 2
+    ("ttl", "below"): (2, [0, 1, 2], 3),           # expiry alone
+    ("ttl", "at"): (6, [0, 1, 2, 3], 3),           # + FIFO: insert 3 ties 4
+}
+
+
+def _eviction_scenario(policy, fill, compiles=None, **kw):
+    """Build the table of ``EVICTION_CASES``, make its final append, and
+    check the evictions, payloads, readback count and the column reader's
+    compiles (one, where it reads at all, unless ``compiles`` says)."""
+    m, want, readbacks = EVICTION_CASES[policy, fill]
+    clock = [0.0]
+    svc = AMService(time_fn=lambda: clock[0], **kw)
+    svc.create_table("t", width=WIDTH, bits=3, capacity=8, policy=policy,
+                     ttl=3.5 if policy == "ttl" else None, backend="ref")
+    rng = np.random.default_rng(22)
+    codes = _codes(rng, 6 + m)
+    assert len(np.unique(codes, axis=0)) == len(codes)
+    _meta_column.clear_cache()
+    for at, lo, hi in ((1.0, 0, 3), (2.0, 3, 5), (4.0, 5, 6)):
+        clock[0] = at
+        svc.append("t", codes[lo:hi], values=list(range(lo, hi)))
+    for at, row in ((4.5, 0), (4.75, 4)):
+        clock[0] = at
+        assert svc.lookup("t", codes[row]).value == row
+    meta = np.asarray(svc._tables["t"].table.meta)[:6]
+    clock[0] = 5.0
+    new = list(range(6, 6 + m))
+    if want is None:
+        with pytest.raises(TableFullError):
+            svc.append("t", codes[6:], values=new)
+        keep = list(range(6))
+    else:
+        assert _evict_reference(meta, policy, 3.5, 5.0, m, 8) == want
+        svc.append("t", codes[6:], values=new)
+        keep = [i for i in range(6) if i not in want] + new
+    s = svc.stats("t")
+    assert s["rows"] == len(keep) and s["evicted"] == len(want or [])
+    assert s["meta_readbacks"] == readbacks
+    assert svc.stats()["meta_readbacks"] == readbacks
+    # the column reader compiles per layout, whatever row counts read it
+    assert _meta_column._cache_size() == (
+        min(readbacks, 1) if compiles is None else compiles)
+    # survivors keep their order, codes and payloads
+    assert svc._tables["t"].values == keep
+    np.testing.assert_array_equal(np.asarray(svc.live_table("t").codes),
+                                  codes[keep])
+    for i in keep:
+        assert svc.lookup("t", codes[i]).value == i
+    for i in set(range(6 + m)) - set(keep):
+        assert not svc.lookup("t", codes[i]).hit
+    return svc
+
+
+@pytest.mark.parametrize("policy,fill", list(EVICTION_CASES))
+def test_make_room_reads_meta_only_where_the_policy_can_evict(policy, fill):
+    _eviction_scenario(policy, fill)
+
+
 def test_delete_and_drop_table():
     rng = np.random.default_rng(13)
     svc = _svc()
@@ -547,6 +631,20 @@ def test_default_mesh_appends_past_capacity_stay_banked():
         np.testing.assert_array_equal(rb.distances, want.distances[i])
         np.testing.assert_array_equal(ra.indices, rb.indices)
         np.testing.assert_array_equal(ra.distances, rb.distances)
+
+
+@pytest.mark.parametrize("policy,compiles", [("lru", 1), ("ttl", 2)])
+def test_banked_eviction_matches_reference(policy, compiles):
+    """Eviction at capacity over a banked slab: the same rows go, the meta
+    column is read once per append that may evict, and the slab stays
+    banked.  On a mesh the dispatch hands the touched meta back replicated
+    as ``P()``, the slab's own layout is ``P(None, None)``: ``ttl`` reads
+    under both (rows 3 and 5 before the lookups, 6 after), ``lru`` only
+    after."""
+    mesh = jax.make_mesh((len(jax.devices()),), ("model",))
+    svc = _eviction_scenario(policy, "at", compiles=compiles, mesh=mesh)
+    slab = svc._tables["t"].table.codes
+    assert slab.sharding.spec[0] == "model" and not slab.is_fully_replicated
 
 
 def test_next_pow2():
